@@ -75,19 +75,24 @@ def _timeit(fn, n=5, warmup=2):
 
 
 def _reexec_with_devices(table: str, fast: bool, child_env: str, n_dev: int = 8):
-    """Multi-device sections re-exec themselves with fake CPU devices when run
-    single-device (the smoke/bench environment pins jax to one visible
-    device).  Returns the child's rows, or None when enough devices are
-    already visible.  One re-exec only: if forcing host devices had no effect
-    (e.g. jax picked a non-CPU backend) the child guard fails fast instead of
-    recursing, and failures raise so the CI gate goes red."""
-    import os
+    """Multi-device sections need ``n_dev`` devices.  Returns None when that
+    many are visible (run in process), else the rows of a child run.
 
-    if jax.device_count() >= n_dev:
+    Only the CPU backend is re-exec'd, with ``n_dev`` fake host devices and
+    ``JAX_PLATFORMS=cpu``.  An accelerator belongs to one process, which this
+    one already holds, so on any other backend too few devices is an error.
+    One re-exec only: a child that still sees too few devices fails instead of
+    recursing, and failures raise so the CI gate goes red."""
+    n = jax.device_count()
+    if n >= n_dev:
         return None
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(f"{table} needs {n_dev} {backend} devices, "
+                           f"this host has {n}")
     if os.environ.get(child_env):
         raise RuntimeError(
-            f"{table}: only {jax.device_count()} device(s) despite "
+            f"{table}: only {n} device(s) despite "
             f"--xla_force_host_platform_device_count={n_dev}")
     import subprocess
     import sys
@@ -95,6 +100,7 @@ def _reexec_with_devices(table: str, fast: bool, child_env: str, n_dev: int = 8)
     env = dict(os.environ)
     flag = f"--xla_force_host_platform_device_count={n_dev}"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env[child_env] = "1"
     cmd = [sys.executable, "-m", "benchmarks.run", "--only", table]
     if fast:
@@ -304,7 +310,6 @@ def table7_moe_noc(fast: bool) -> list[str]:
     from repro.core.noc import NoCConfig
     from repro.core.routing import compile_routes, route_program_stats
     from repro.core.topology import make_topology
-    from repro.launch.mesh import set_mesh
     from repro.models import moe as M
     from repro.models.layers import init_params
 
@@ -331,7 +336,7 @@ def table7_moe_noc(fast: bool) -> list[str]:
 
         return jax.jit(f), holder
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ref, _, _ = M.moe_apply(params, x, base)
         prev_drops = None
         for depth in depths:
@@ -906,7 +911,7 @@ def fig_pf(fast: bool) -> list[str]:
 
 def lm_step(fast: bool) -> list[str]:
     from repro.configs import get_config
-    from repro.launch.mesh import make_host_mesh, set_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import make_train_step
     from repro.models import transformer as T
     from repro.models.layers import init_params
@@ -926,7 +931,7 @@ def lm_step(fast: bool) -> list[str]:
                  "labels": jnp.asarray(rng.integers(0, cfg.vocab, (B, S)), jnp.int32)}
         if cfg.family == "encdec":
             batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_frontend), jnp.float32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(make_train_step(cfg, mesh, AdamWConfig()))
             state, _ = step(state, batch)  # compile
             t = _timeit(lambda: jax.block_until_ready(step(state, batch)[1]["loss"]), n=3)
@@ -1038,6 +1043,9 @@ def main() -> None:
                          "against the committed BENCH_*.json baselines "
                          "(delegates to repro.telemetry.regress)")
     args, extra = ap.parse_known_args()
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     if args.compare:
         from repro.telemetry.regress import main as regress_main
 

@@ -26,7 +26,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from ..core import (NoCExecutor, PE, Port, TaskGraph, cut, make_topology,
                     resolve_placement)
 from ..core.routing import all_to_all_for, topology_axes
@@ -214,7 +213,7 @@ def iterate_spmd(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
             for _ in range(r):
                 out = local(lut_loc, out)
             return out
-        sm = shard_map(fn, mesh=mesh, in_specs=(lspec, vspec),
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=(lspec, vspec),
                        out_specs=vspec, check_vma=False)
         return sm(lut_, vw_)
 

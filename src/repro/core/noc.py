@@ -105,7 +105,7 @@ construction time.  ``NoCExecutor.__init__`` therefore compiles, per wave, a
 wave instead of per-message Python loops; ``run_iterative`` reuses the
 compiled program across all iterations, and ``run_batch`` moves B independent
 input sets through the topology in a single ``(B, n, n, bytes)`` simulation.
-PE bodies are jit-cached per PE (with a transparent eager fallback), so the
+PE bodies are jit-cached per distinct body, so the
 firing side of the wave is compiled once as well.
 
 Flit accounting mirrors CONNECT's link model (default flit_data_width=16,
@@ -398,12 +398,9 @@ class NoCExecutor:
             self._chan_by_src[c.src_pe].append(c)
         self.programs: list[_WaveProgram] = [self._compile_wave(w) for w in self.waves]
         self._hop_cache: dict[tuple[int, int], int] = {}   # (src, dst) -> hops
-        # jit caches for PE firing (sim/batch modes), keyed by id(pe.fn);
-        # fall back to eager per distinct body
+        # jit caches for PE firing (sim/batch modes), keyed by id(pe.fn)
         self._jit_fns: dict[int, Any] = {}
-        self._jit_ok: dict[int, bool] = {}
         self._vmap_fns: dict[int, Any] = {}
-        self._vmap_ok: dict[int, bool] = {}
         # spmd lowering (mode="spmd") is built lazily on first use: it needs
         # n_nodes real/fake devices, which sim-only runs must not require.
         # The bridged program (plan=) is likewise compiled on first partitioned
@@ -495,34 +492,20 @@ class NoCExecutor:
     # executor's lifetime, so id() keys are stable.
 
     def _fire(self, name: str, kwargs: dict[str, Any]) -> Mapping[str, Any]:
-        """Call a PE body through the jit cache; eager fallback on failure."""
-        pe = self.graph.pes[name]
-        key = id(pe.fn)
-        if self._jit_ok.get(key, True):
-            fn = self._jit_fns.get(key)
-            if fn is None:
-                fn = self._jit_fns[key] = jax.jit(pe.fn)
-            try:
-                return fn(**kwargs)
-            except Exception:
-                self._jit_ok[key] = False
-        return pe.fn(**kwargs)
+        """Call a PE body through the jit cache."""
+        fn = self.graph.pes[name].fn
+        jitted = self._jit_fns.get(id(fn))
+        if jitted is None:
+            jitted = self._jit_fns[id(fn)] = jax.jit(fn)
+        return jitted(**kwargs)
 
-    def _fire_batch(self, name: str, kwargs: dict[str, Any], B: int) -> Mapping[str, Any]:
-        """Fire one PE on B stacked input sets; vmap with per-item fallback."""
-        pe = self.graph.pes[name]
-        key = id(pe.fn)
-        if self._vmap_ok.get(key, True):
-            fn = self._vmap_fns.get(key)
-            if fn is None:
-                fn = self._vmap_fns[key] = jax.jit(jax.vmap(pe.fn))
-            try:
-                return fn(**kwargs)
-            except Exception:
-                self._vmap_ok[key] = False
-        items = [pe.fn(**{k: v[b] for k, v in kwargs.items()}) for b in range(B)]
-        return {p.name: np.stack([np.asarray(it[p.name]) for it in items])
-                for p in pe.outputs}
+    def _fire_batch(self, name: str, kwargs: dict[str, Any]) -> Mapping[str, Any]:
+        """Fire one PE on stacked input sets (leading batch axis) via vmap."""
+        fn = self.graph.pes[name].fn
+        batched = self._vmap_fns.get(id(fn))
+        if batched is None:
+            batched = self._vmap_fns[id(fn)] = jax.jit(jax.vmap(fn))
+        return batched(**kwargs)
 
     # -- spmd lowering -------------------------------------------------------
     def _ensure_spmd(self) -> None:
@@ -538,7 +521,6 @@ class NoCExecutor:
             return
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
         from .partition import mesh_for_partition, mesh_for_topology
         from .routing import compile_routes, run_route_program
 
@@ -566,7 +548,7 @@ class NoCExecutor:
                 x = local.reshape(local.shape[n_lead:])
                 return run_route_program(x, prog).reshape(local.shape)
 
-        sm = shard_map(device_fn, mesh=mesh, in_specs=P(*names),
+        sm = jax.shard_map(device_fn, mesh=mesh, in_specs=P(*names),
                        out_specs=P(*names), check_vma=False)
         self._spmd_fn = jax.jit(sm)
 
@@ -686,9 +668,9 @@ class NoCExecutor:
         """Run B independent input sets at once; every input carries a leading
         batch axis ``(B, *port.shape)`` and so does every output.
 
-        ``sim`` fires each PE once on the stacked batch (vmap, with a per-item
-        eager fallback) and moves all B message sets through the topology in a
-        single ``(B, n, n, bytes)`` :func:`simulate_schedule` call.  Stats:
+        ``sim`` fires each PE once on the stacked batch (vmap) and moves
+        all B message sets through the topology in a single
+        ``(B, n, n, bytes)`` :func:`simulate_schedule` call.  Stats:
         waves/rounds are physical (counted once — the batch shares the
         schedule), while payload/flit/link/cross-pod byte counters scale with
         B (each input set's messages really occupy the links)."""
@@ -749,7 +731,7 @@ class NoCExecutor:
                 pe = g.pes[name]
                 kwargs = {p.name: mailbox[(name, p.name)] for p in pe.inputs}
                 results = (self._fire(name, kwargs) if B is None
-                           else self._fire_batch(name, kwargs, B))
+                           else self._fire_batch(name, kwargs))
                 for p in pe.outputs:
                     mailbox[(name, p.name)] = np.asarray(results[p.name])
             if not prog.slots:

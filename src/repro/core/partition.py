@@ -532,24 +532,16 @@ def named_sharding(mesh: Mesh, axes: Sequence[Optional[str]],
 
 def constrain(x: jax.Array, axes: Sequence[Optional[str]],
               rules: Mapping[str, Any] = DEFAULT_RULES) -> jax.Array:
-    """with_sharding_constraint by logical axes (no-op outside jit/mesh);
-    shape-aware: unshardable dims stay replicated."""
-    try:
-        from ..compat import MODERN_SHARD_MAP, get_abstract_mesh, manual_axes_in_scope
-        mesh = get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        manual = manual_axes_in_scope()
-        if manual and not MODERN_SHARD_MAP:
-            return x  # constraint hints inside partial-manual regions crash old XLA
-        usable = tuple(a for a in mesh.axis_names if a not in manual)
-        if not usable:
-            return x
-        spec = logical_to_spec(axes, rules, usable, dims=x.shape,
-                               mesh_shape=dict(mesh.shape))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    """with_sharding_constraint by logical axes over the ambient mesh's
+    non-manual axes (a no-op when there are none); shape-aware: unshardable
+    dims stay replicated."""
+    mesh = jax.sharding.get_abstract_mesh()
+    usable = tuple(a for a in mesh.axis_names if a not in mesh.manual_axes)
+    if not usable:
         return x
+    spec = logical_to_spec(axes, rules, usable, dims=x.shape,
+                           mesh_shape=dict(mesh.shape))
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # -- cross-pod gradient exchange (the "cut link" of the LM framework) --------

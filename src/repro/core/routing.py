@@ -27,7 +27,6 @@ from jax import lax
 import dataclasses
 from typing import Optional
 
-from ..compat import axis_size
 from .topology import (AxisSchedule, FatTree, Mesh2D, Ring, Topology, Torus2D,
                        bwd_pairs, fwd_pairs)
 
@@ -59,7 +58,7 @@ def _put(out: jax.Array, src, val: jax.Array, valid) -> jax.Array:
 
 def ring_all_to_all_unidir(x: jax.Array, axis_name: str) -> jax.Array:
     """Paper-faithful unidirectional ring rotation: n-1 rounds."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     me = lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
     out = _put(jnp.zeros_like(x), i, me, True)
@@ -76,7 +75,7 @@ def ring_all_to_all_unidir(x: jax.Array, axis_name: str) -> jax.Array:
 def line_all_to_all(x: jax.Array, axis_name: str, wrap: bool) -> jax.Array:
     """Bidirectional 1D exchange.  wrap=True → torus ring (⌈n/2⌉-ish rounds,
     both directions concurrently); wrap=False → mesh line (n-1 rounds)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     me = lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
     out = _put(jnp.zeros_like(x), i, me, True)
@@ -103,8 +102,8 @@ def grid_all_to_all(x: jax.Array, axis_x: str, axis_y: str, wrap: bool) -> jax.A
     """Factorized 2D exchange (dimension-ordered routing, like XY routing in
     the paper's mesh/torus NoCs).  ``x``: (n, *chunk), destination linear index
     d = dy*rx + dx;  returns source-linear-indexed result."""
-    rx = axis_size(axis_x)
-    ry = axis_size(axis_y)
+    rx = lax.axis_size(axis_x)
+    ry = lax.axis_size(axis_y)
     c = x.shape[1:]
     b = x.reshape(ry, rx, *c)          # (dy, dx, *c)
     b = jnp.moveaxis(b, 1, 0)          # (dx, dy, *c)

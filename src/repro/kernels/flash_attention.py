@@ -67,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, bq: int = 128, bkv: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: (B, Hq, S, D); k/v: (B, Hkv, T, D), Hq % Hkv == 0 -> (B, Hq, S, D)."""
     B, Hq, S, D = q.shape
     _, Hkv, T, _ = k.shape

@@ -3,14 +3,16 @@
 FPGA→TPU adaptation: the paper maps the precomputed LUTs to BRAM and
 XOR-accumulates incoming k-bit flits at each processing node.  Here each grid
 step c streams one column-tile's LUT slab HBM→VMEM, the packed sub-vector
-word ``v[m, c]`` (scalar-prefetched to SMEM — the "partition index" flit)
-selects one of the 2^k LUT rows, and the XOR accumulation happens in the
+word ``v[m, c]`` (read from SMEM — the "partition index" flit) selects one of
+the 2^k LUT rows, and the XOR accumulation happens in the
 revisited VMEM output block — the VPU-resident restatement of the BRAM-lookup
 + XOR-tree datapath.
 
 Layout: LUT (C, 2^k, R) uint32, R padded to a multiple of 128 (lane dim);
 the 2^k axis is the sublane axis.  Grid = (M_blocks, C); output block
-(BM, R) is revisited across the C axis (reduction pattern).
+(BM, R) is revisited across the C axis (reduction pattern).  The index words
+arrive as one (BM, C) SMEM block per M block, not as a scalar prefetch of the
+whole (M, C) array: SMEM holds 1 MiB, which M=1024 vectors at C=512 exceed.
 """
 from __future__ import annotations
 
@@ -29,18 +31,17 @@ def _kernel(v_ref, lut_ref, out_ref, *, bm: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    m0 = pl.program_id(0) * bm
     # lut_ref block: (1, 2^k, R); select the partition row per batch element
     # (the flit "partition index" v[m, c]) and XOR into the accumulator.
     for dm in range(bm):  # bm is small & static; unrolled gather over sublanes
-        idx = v_ref[m0 + dm, c]
+        idx = v_ref[dm, c]
         row = lut_ref[0, idx, :]
         out_ref[dm, :] = jnp.bitwise_xor(out_ref[dm, :], row)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def gf2_bmvm_pallas(lut: jax.Array, v_words: jax.Array, *, bm: int = 8,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """lut: (C, P=2^k, R) uint32;  v_words: (M, C) uint32 -> (M, R) uint32."""
     C, P, R = lut.shape
     M = v_words.shape[0]
@@ -52,12 +53,11 @@ def gf2_bmvm_pallas(lut: jax.Array, v_words: jax.Array, *, bm: int = 8,
     grid = (Mp // bm, C)
     out = pl.pallas_call(
         functools.partial(_kernel, bm=bm),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, P, R), lambda m, c, v: (c, 0, 0))],
-            out_specs=pl.BlockSpec((bm, R), lambda m, c, v: (m, 0)),
-        ),
+        grid=grid,
+        in_specs=[pl.BlockSpec((bm, C), lambda m, c: (m, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, P, R), lambda m, c: (c, 0, 0))],
+        out_specs=pl.BlockSpec((bm, R), lambda m, c: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, R), jnp.uint32),
         interpret=interpret,
     )(v_words.astype(jnp.int32), lut)

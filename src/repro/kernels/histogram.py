@@ -47,7 +47,7 @@ def _kernel(bins_ref, w_ref, ref_ref, hist_ref, bc_ref, *, n_bins: int, n_px: in
 @functools.partial(jax.jit, static_argnames=("n_bins", "bn", "bpx", "interpret"))
 def particle_histogram_pallas(bins: jax.Array, weights: jax.Array, ref_hist: jax.Array,
                               *, n_bins: int, bn: int = 8, bpx: int = 512,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """bins: (N, px) int32; weights: (px,); ref_hist: (n_bins,)
     -> (hist (N, n_bins), bc (N,))."""
     N, px = bins.shape

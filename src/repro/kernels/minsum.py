@@ -17,8 +17,12 @@ from jax.experimental import pallas as pl
 def _kernel(u_ref, out_ref):
     u = u_ref[...]
     mag = jnp.abs(u)
-    sgn = jnp.where(u < 0, -1.0, 1.0).astype(u.dtype)
-    total_sign = jnp.prod(sgn, axis=-1, keepdims=True)
+    neg = u < 0
+    sgn = jnp.where(neg, -1.0, 1.0).astype(u.dtype)
+    # product of the ±1 signs as the parity of the negative count: Mosaic has
+    # no reduce_prod lowering, and the result is exact either way
+    n_neg = jnp.sum(neg.astype(jnp.int32), axis=-1, keepdims=True)
+    total_sign = jnp.where((n_neg & 1) == 1, -1.0, 1.0).astype(u.dtype)
     min1 = jnp.min(mag, axis=-1, keepdims=True)
     amin = jnp.argmin(mag, axis=-1)
     is_min = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 1) == amin[:, None]
@@ -28,7 +32,7 @@ def _kernel(u_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "interpret"))
-def minsum_check_pallas(u: jax.Array, *, bc: int = 256, interpret: bool = True) -> jax.Array:
+def minsum_check_pallas(u: jax.Array, *, bc: int = 256, interpret: bool = False) -> jax.Array:
     """u: (n_checks, deg) f32 -> (n_checks, deg) check-to-bit messages."""
     n, deg = u.shape
     bc = min(bc, n)

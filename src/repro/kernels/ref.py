@@ -77,8 +77,12 @@ def minsum_check(u: jax.Array) -> jax.Array:
     this is the standard general form — reduces to it for positive inputs.)
     """
     mag = jnp.abs(u)
-    sgn = jnp.where(u < 0, -1.0, 1.0).astype(u.dtype)
-    total_sign = jnp.prod(sgn, axis=-1, keepdims=True)
+    neg = u < 0
+    sgn = jnp.where(neg, -1.0, 1.0).astype(u.dtype)
+    # prod of the ±1 signs, as the parity of the negative count: a batched
+    # reduce-multiply here crashes the TPU compiler's fusion pass
+    n_neg = jnp.sum(neg.astype(jnp.int32), axis=-1, keepdims=True)
+    total_sign = jnp.where((n_neg & 1) == 1, -1.0, 1.0).astype(u.dtype)
     min1 = jnp.min(mag, axis=-1, keepdims=True)
     amin = jnp.argmin(mag, axis=-1)
     masked = jnp.where(jax.nn.one_hot(amin, u.shape[-1], dtype=bool), jnp.inf, mag)

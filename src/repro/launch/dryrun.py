@@ -27,9 +27,11 @@ import json
 import time
 import traceback
 
+import jax
+
 from ..configs import ALL_ARCHS, SHAPES, cell_supported, get_config
 from . import roofline as RL
-from .mesh import make_production_mesh, set_mesh
+from .mesh import make_production_mesh
 from .steps import jit_decode, jit_prefill, jit_train_step
 
 
@@ -117,7 +119,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool,
     else:
         rules_ctx = contextlib.nullcontext()
     try:
-        with set_mesh(mesh), rules_ctx:
+        with jax.set_mesh(mesh), rules_ctx:
             lowered = _lower_cell(cfg, shape, mesh, step_kw)
             t_lower = time.monotonic() - t0
             compiled = lowered.compile()

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from ..configs import get_config
 from ..models import transformer as T
 from ..models.layers import init_params
-from .mesh import make_host_mesh, set_mesh
+from .cache import use_compile_cache
+from .mesh import make_host_mesh
 
 
 def serve_batch(params, cfg, prompts: np.ndarray, gen: int, mesh,
@@ -35,7 +36,7 @@ def serve_batch(params, cfg, prompts: np.ndarray, gen: int, mesh,
     ``serve.prefill.seconds`` / ``serve.decode.seconds`` histograms (each
     sample is synced via the host round-trip, so it bounds real latency)."""
     B, S = prompts.shape
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache = T.init_cache(cfg, B, S + gen)
         batch = {"tokens": jnp.asarray(prompts)}
         if cfg.family == "encdec":
@@ -76,6 +77,7 @@ def run(argv=None):
                     help="enable the telemetry metrics registry; write the "
                          "JSON snapshot here ('-' prints to stdout)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     reg = None
     if args.metrics:

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import ModelConfig, ShapeConfig
 from ..core.partition import DEFAULT_RULES, cross_pod_mean
 from ..core.serdes import QuasiSerdesConfig
@@ -56,7 +55,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt_cfg: AdamWConfig,
                     total_steps: int = 10_000, warmup: int = 200):
     """pod_sync:
       'auto'   — flat XLA all-reduce over (pod, data)  [baseline]
-      'serdes' — per-pod grads via shard_map(auto over data/model), cross-pod
+      'serdes' — per-pod grads in a fully-manual shard_map, cross-pod
                  exchange through quasi-SERDES endpoints  [paper-faithful cut]
     """
     n_pods = mesh.shape.get("pod", 1)
@@ -72,15 +71,16 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt_cfg: AdamWConfig,
     def grads_serdes(params, batch):
         """Fully-manual shard_map region (manual over *every* mesh axis).
 
-        The earlier partial-manual lowering (manual over 'pod' only, data/model
-        auto inside) trips old XLA's ``sharding.IsManualSubgroup()`` check on
-        the pinned jax 0.4.37.  Fully-manual sidesteps it on old and new jax
-        alike: params enter replicated, each device computes grads on its own
-        (pod × data) batch shard, the within-pod average is an explicit pmean
-        over 'data' (the on-chip all-reduce), and only the cross-pod exchange
-        goes through the quasi-SERDES endpoints over the cut.  Model-axis
-        devices redundantly compute identical grads — the replication that
-        makes the region's outputs valid under ``out_specs=P()``."""
+        A partial-manual region (``axis_names={'pod'}``, data/model auto
+        inside) still aborts XLA's SPMD partitioner on jax 0.9 (a
+        ``partition_group_list`` check in spmd_partitioner_util).
+        Fully-manual sidesteps it: params enter replicated, each device
+        computes grads on its own (pod × data) batch shard, the within-pod
+        average is an explicit pmean over 'data' (the on-chip all-reduce),
+        and only the cross-pod exchange goes through the quasi-SERDES
+        endpoints over the cut.  Model-axis devices redundantly compute
+        identical grads — the replication that makes the region's outputs
+        valid under ``out_specs=P()``."""
         data_axes = tuple(a for a in ("data",) if a in mesh.axis_names)
         sync_axes = ("pod",) + data_axes
 
@@ -96,7 +96,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt_cfg: AdamWConfig,
 
         blead = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         bspec = jax.tree.map(lambda _: P(blead), batch)
-        return shard_map(
+        return jax.shard_map(
             pod_local, mesh=mesh,
             in_specs=(P(), bspec), out_specs=(P(), P(), P()),
             check_vma=False)(params, batch)

@@ -28,7 +28,8 @@ from ..models import transformer as T
 from ..models.layers import init_params
 from ..optim import AdamWConfig, adamw_init
 from ..runtime import FTConfig, ResilientRunner
-from .mesh import make_host_mesh, set_mesh
+from .cache import use_compile_cache
+from .mesh import make_host_mesh
 from .steps import make_train_step, shardings_for_params
 
 
@@ -40,7 +41,7 @@ def build_state(cfg, mesh, seed: int = 0):
     def init(key):
         return init_params(specs, key)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(init, out_shardings=psh)(jax.random.key(seed))
         opt = jax.jit(adamw_init, out_shardings=None)(params)
     return {"params": params, "opt": opt}
@@ -64,6 +65,7 @@ def run(argv=None):
                     help="enable the telemetry metrics registry; write the "
                          "JSON snapshot here ('-' prints to stdout)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     reg = None
     if args.metrics:
@@ -85,7 +87,7 @@ def run(argv=None):
                       seed=args.seed)
     pipeline = ShardedTokenPipeline(dcfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, donate_argnums=(0,))
         losses = []
 

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import get_abstract_mesh, shard_map
 from ..core.noc import NoCConfig
 from .layers import ParamSpec
 
@@ -351,8 +350,8 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
         out, aux = dense_ref(params, x, c)
         return out, aux, _static_stats("dense", c)
 
-    mesh = get_abstract_mesh()
-    if mesh is None or "model" not in (mesh.axis_names or ()):
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         # no mesh context (unit tests / single host): run the oracle
         out, aux = dense_ref(params, x, c)
         return out, aux, _static_stats(
@@ -407,7 +406,7 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
                 peak = lax.pmax(peak, batch_axes)
             return (out.reshape(xl.shape), _aux_of(me, ce, batch_axes),
                     drops, peak)
-        sm = shard_map(
+        sm = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(bspec, None, None), P(), wspec, wspec, wspec),
             out_specs=(P(bspec, None, None), P(), P(), P()),
@@ -440,7 +439,7 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
             xl2, wr, wg, wu, wd, c, n_ranks, "model", prog, cap)
         return (out.reshape(xl.shape), _aux_of(me, ce, all_axes),
                 lax.psum(drops, all_axes), lax.pmax(peak, all_axes))
-    sm = shard_map(
+    sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(), wspec, wspec, wspec),
         out_specs=(P(bspec, "model", None), P(), P(), P()),

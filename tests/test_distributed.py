@@ -12,7 +12,6 @@ def test_moe_engines_agree_across_mesh():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
-from repro.launch.mesh import set_mesh
 from repro.models import moe as M
 from repro.models.layers import init_params
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
@@ -25,7 +24,7 @@ engines = [M.MoEConfig(32, 8, 2, 64, capacity_factor=8.0, impl="gather")]
 engines += [M.MoEConfig(32, 8, 2, 64, capacity_factor=8.0, impl="noc",
                         noc_topology=t)
             for t in ("fattree", "ring", "mesh2d", "torus2d")]
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ref, aux_ref, _ = M.moe_apply(params, x, dense)
     for c in engines:
         out, aux, st = M.moe_apply(params, x, c)
@@ -47,7 +46,6 @@ def test_moe_noc_ring_schedule():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
-from repro.launch.mesh import set_mesh
 from repro.models import moe as M
 from repro.models.layers import init_params
 mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
@@ -56,7 +54,7 @@ dense = M.MoEConfig(32, 8, 2, 64, capacity_factor=8.0, impl="dense")
 ring = M.MoEConfig(32, 8, 2, 64, capacity_factor=8.0, impl="noc", noc_topology="ring")
 params = init_params(M.moe_specs(dense), jax.random.key(0))
 x = jnp.asarray(rng.normal(size=(2, 8, 32)), jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ref, _, _ = M.moe_apply(params, x, dense)
     out, _, st = M.moe_apply(params, x, ring)
 assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
@@ -74,7 +72,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.configs import get_config
 from repro.core.serdes import QuasiSerdesConfig
-from repro.launch.mesh import set_mesh
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 from repro.models.layers import init_params
@@ -88,7 +85,7 @@ batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32),
          "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32)}
 opt = AdamWConfig(lr=1e-3)
 outs = {}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for name, kw in [("auto", dict(pod_sync="auto")),
                      ("serdes_none", dict(pod_sync="serdes",
                                           serdes=QuasiSerdesConfig(compress="none"))),
@@ -120,7 +117,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.checkpoint import CheckpointConfig, CheckpointManager
 from repro.configs import get_config
-from repro.launch.mesh import set_mesh
 from repro.launch.steps import make_train_step, shardings_for_params
 from repro.models import transformer as T
 from repro.models.layers import init_params
@@ -132,7 +128,7 @@ state = {{"params": params, "opt": adamw_init(params)}}
 rng = np.random.default_rng(0)
 batch = {{"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32),
          "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32)}}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(make_train_step(cfg, mesh, AdamWConfig(lr=1e-3)))
     for _ in range(4):
         state, mets = step(state, batch)
@@ -145,7 +141,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.checkpoint import CheckpointConfig, CheckpointManager
 from repro.configs import get_config
-from repro.launch.mesh import set_mesh
 from repro.launch.steps import make_train_step, shardings_for_params
 from repro.models import transformer as T
 from repro.models.layers import init_params
@@ -164,7 +159,7 @@ assert step_no == 4
 rng = np.random.default_rng(0)
 batch = {{"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32),
          "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)), jnp.int32)}}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(make_train_step(cfg, mesh, AdamWConfig(lr=1e-3)))
     state, mets = step(state, batch)
 assert np.isfinite(float(mets["loss"]))

@@ -448,7 +448,6 @@ def test_spmd_bridged_route_program_matches_oracle():
     run_with_devices("""
 import numpy as np, jax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import compile_bridges, compile_routes, make_topology
 from repro.core.interchip import BridgeConfig, run_bridged_program
 from repro.core.partition import PartitionPlan
@@ -475,7 +474,7 @@ for name in ("ring", "mesh", "torus", "fattree"):
             x = local.reshape(local.shape[len(sizes):])
             return run_bridged_program(x, bprog, names).reshape(local.shape)
         cube = rng.integers(0, 255, (n, n, 7)).astype(np.uint8)
-        sm = shard_map(device_fn, mesh=mesh, in_specs=P(*names),
+        sm = jax.shard_map(device_fn, mesh=mesh, in_specs=P(*names),
                        out_specs=P(*names), check_vma=False)
         out = np.asarray(jax.jit(sm)(cube.reshape(tuple(sizes) + (n, 7))))
         assert np.array_equal(out.reshape(n, n, 7), cube.swapaxes(0, 1)), (name, pods)

@@ -70,7 +70,6 @@ def test_linearized_route_program_matches_oracle():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import compile_routes, make_topology, run_route_program, transpose_oracle
 for n in (4, 8):
     mesh = Mesh(np.array(jax.devices()[:n]), ("model",))
@@ -83,7 +82,7 @@ for n in (4, 8):
                                      axis_name="model").reshape(xl.shape)
         def oracle(xl):
             return transpose_oracle(xl.reshape(n, -1), "model").reshape(xl.shape)
-        sm = lambda f: shard_map(f, mesh=mesh, in_specs=P("model"),
+        sm = lambda f: jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                                  out_specs=P("model"), check_vma=False)
         got = np.asarray(sm(routed)(x))
         want = np.asarray(sm(oracle)(x))
@@ -104,7 +103,6 @@ from jax.sharding import Mesh
 from repro.core.routing import compile_routes, route_program_stats
 from repro.core.noc import NoCConfig
 from repro.core.topology import make_topology
-from repro.launch.mesh import set_mesh
 from repro.models import moe as M
 from repro.models.layers import init_params
 n = 8
@@ -115,7 +113,7 @@ dense = M.MoEConfig(d, E, k, 48, impl="dense")
 params = init_params(M.moe_specs(dense), jax.random.key(0))
 x = jnp.asarray(rng.normal(size=(2, 32, d)), jnp.float32)
 ncfg = NoCConfig(flit_buffer_depth=4)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ref, _, _ = M.moe_apply(params, x, dense)
     for topo in ("fattree", "ring", "mesh2d", "torus2d"):
         c = M.MoEConfig(d, E, k, 48, impl="noc", noc_topology=topo, noc=ncfg)
@@ -145,7 +143,6 @@ def test_moe_capacity_parity_gather_vs_noc():
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.core.noc import NoCConfig
-from repro.launch.mesh import set_mesh
 from repro.models import moe as M
 from repro.models.layers import init_params
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
@@ -154,7 +151,7 @@ base = M.MoEConfig(d_model=32, n_experts=8, top_k=2, d_ff=64, impl="dense")
 params = init_params(M.moe_specs(base), jax.random.key(0))
 x = jnp.asarray(rng.normal(size=(4, 16, 32)), jnp.float32)
 prev = None
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for depth in (1, 2, 4, 8):
         ncfg = NoCConfig(flit_buffer_depth=depth)
         og, _, sg = M.moe_apply(params, x, M.MoEConfig(
@@ -183,13 +180,12 @@ def test_moe_fallback_reasons_and_warnings():
 import warnings
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
-from repro.launch.mesh import set_mesh
 from repro.models import moe as M
 from repro.models.layers import init_params
 mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
 rng = np.random.default_rng(3)
 x = jnp.asarray(rng.normal(size=(2, 8, 32)), jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     # trigger 1: n_experts % n_ranks != 0 -> dense_ref (perf cliff), warns
     bad = M.MoEConfig(32, 6, 2, 64, impl="gather")
     params = init_params(M.moe_specs(bad), jax.random.key(0))
@@ -231,7 +227,6 @@ def test_moe_stats_thread_through_transformer():
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.configs import get_config
-from repro.launch.mesh import set_mesh
 from repro.models import transformer as T
 from repro.models.layers import init_params
 mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
@@ -241,7 +236,7 @@ params = init_params(T.abstract_params(cfg), jax.random.key(0))
 rng = np.random.default_rng(0)
 batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)), jnp.int32),
          "labels": jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)), jnp.int32)}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     loss, mets = T.loss(params, batch, cfg)
 assert np.isfinite(float(loss))
 assert "moe_drops" in mets and "moe_peak_occupancy" in mets

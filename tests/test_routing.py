@@ -36,7 +36,6 @@ def test_shard_map_schedules_match_oracle():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import make_topology
 from repro.core.routing import all_to_all_for, topology_axes
 for name in ("ring","mesh","torus","fattree"):
@@ -48,7 +47,7 @@ for name in ("ring","mesh","torus","fattree"):
         fn = all_to_all_for(topo)
         x = jnp.arange(n*n*3, dtype=jnp.float32).reshape(n, n, 3)
         in_spec = P(tuple(a for a,_ in axes)) if len(axes)>1 else P(axes[0][0])
-        sm = shard_map(lambda b: fn(b.reshape(n, 3)).reshape(1, n, 3),
+        sm = jax.shard_map(lambda b: fn(b.reshape(n, 3)).reshape(1, n, 3),
                        mesh=mesh, in_specs=in_spec, out_specs=in_spec,
                        check_vma=False)
         out = np.asarray(sm(x))

@@ -109,7 +109,6 @@ def test_run_route_program_matches_oracle_on_devices():
     run_with_devices("""
 import numpy as np, jax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import compile_routes, make_topology
 from repro.core.routing import run_route_program
 for name in ("ring", "mesh", "torus", "fattree"):
@@ -124,7 +123,7 @@ for name in ("ring", "mesh", "torus", "fattree"):
             return run_route_program(x, prog).reshape(local.shape)
         rng = np.random.default_rng(n)
         cube = rng.integers(0, 255, (n, n, 7)).astype(np.uint8)
-        sm = shard_map(device_fn, mesh=mesh, in_specs=P(*names),
+        sm = jax.shard_map(device_fn, mesh=mesh, in_specs=P(*names),
                        out_specs=P(*names), check_vma=False)
         out = np.asarray(jax.jit(sm)(cube.reshape(sizes + [n, 7])))
         assert np.array_equal(out.reshape(n, n, 7), cube.swapaxes(0, 1)), (name, n)
