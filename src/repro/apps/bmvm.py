@@ -19,6 +19,7 @@ LUT — here simply n/k/f PEs each owning f LUT columns.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import numpy as np
@@ -67,13 +68,16 @@ def software_ref(a_bits: np.ndarray, v_bits: np.ndarray, r: int) -> np.ndarray:
 def iterate_kernel(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
                    use_kernel: bool = True) -> jax.Array:
     """A^r·V via the Pallas kernel; v_bits: (M, n) -> (M, n)."""
-    vw = kref.gf2_pack_vector(v_bits, cfg.k).astype(jnp.uint32)
+    with jax.named_scope("bmvm.pack"):
+        vw = kref.gf2_pack_vector(v_bits, cfg.k).astype(jnp.uint32)
 
     def body(vw, _):
-        return kops.gf2_bmvm(lut, vw, use_kernel=use_kernel), None
+        with jax.named_scope("kernels.gf2_bmvm"):
+            return kops.gf2_bmvm(lut, vw, use_kernel=use_kernel), None
 
     vw, _ = jax.lax.scan(body, vw, None, length=r)
-    return kref.gf2_unpack_vector(vw, cfg.k)
+    with jax.named_scope("bmvm.unpack"):
+        return kref.gf2_unpack_vector(vw, cfg.k)
 
 
 # ---------------------------------------------------------------------------
@@ -161,13 +165,18 @@ def iterate_noc_sim(lut: jax.Array, v_bits: np.ndarray, cfg: BMVMConfig, r: int,
 # SPMD (shard_map) realization — the production path
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.profiler.annotate_function, name="bmvm.spmd.build")
 def iterate_spmd(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
                  mesh=None, topology: str = "fattree"):
     """Distribute PEs over mesh devices; route via the topology schedule.
 
     lut (C, P, R) sharded over PEs on axis 0; v words likewise.  Each round:
     local lookup (C_loc rows of all R words) -> all-to-all (each node keeps
-    its R_loc words from everyone) -> XOR-reduce."""
+    its R_loc words from everyone) -> XOR-reduce.
+
+    The host span ``bmvm.spmd.build`` covers the Python work of a call: the
+    topology, mesh and route plan, and tracing the program.  Under a caller's
+    ``jax.jit`` it fires only when that caller traces."""
     from jax.sharding import Mesh, PartitionSpec as P_
 
     topo = make_topology(topology, (mesh.devices.size if mesh else jax.device_count()))
@@ -180,7 +189,8 @@ def iterate_spmd(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
     C, P2k, R = lut.shape
     assert C % n_nodes == 0 and R % n_nodes == 0
     r_loc = R // n_nodes
-    vw = kref.gf2_pack_vector(v_bits, cfg.k).astype(jnp.uint32)   # (M, C)
+    with jax.named_scope("bmvm.pack"):
+        vw = kref.gf2_pack_vector(v_bits, cfg.k).astype(jnp.uint32)   # (M, C)
     M = vw.shape[0]
     mesh_axes = tuple(a for a, _ in axes)
     lspec = P_(mesh_axes if len(mesh_axes) > 1 else mesh_axes[0], None, None)
@@ -189,18 +199,20 @@ def iterate_spmd(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
     def local(lut_loc, vw_loc):
         # vw_loc: (M, C_loc) this node's sub-vector words
         def body(vw_l, _):
-            looked = jax.vmap(
-                lambda vrow: jax.vmap(lambda lc, vv: lc[vv, :])(lut_loc, vrow)
-            )(vw_l)                                             # (M, C_loc, R)
-            part = looked[:, 0]
-            for c in range(1, looked.shape[1]):
-                part = jnp.bitwise_xor(part, looked[:, c])      # (M, R) local partial
-            # packetize per destination node: dest j gets words [j*r_loc:(j+1)*r_loc]
-            pkts = part.reshape(M, n_nodes, r_loc).swapaxes(0, 1)  # (n, M, r_loc)
+            with jax.named_scope("bmvm.lookup"):
+                looked = jax.vmap(
+                    lambda vrow: jax.vmap(lambda lc, vv: lc[vv, :])(lut_loc, vrow)
+                )(vw_l)                                         # (M, C_loc, R)
+                part = looked[:, 0]
+                for c in range(1, looked.shape[1]):
+                    part = jnp.bitwise_xor(part, looked[:, c])  # (M, R) local partial
+                # packetize per destination node: dest j gets words [j*r_loc:(j+1)*r_loc]
+                pkts = part.reshape(M, n_nodes, r_loc).swapaxes(0, 1)  # (n, M, r_loc)
             rcv = a2a(pkts)                                      # (n, M, r_loc)
-            acc = rcv[0]
-            for s in range(1, n_nodes):
-                acc = jnp.bitwise_xor(acc, rcv[s])               # (M, r_loc) = my words
+            with jax.named_scope("bmvm.reduce"):
+                acc = rcv[0]
+                for s in range(1, n_nodes):
+                    acc = jnp.bitwise_xor(acc, rcv[s])           # (M, r_loc) = my words
             return acc, None
 
         acc, _ = jax.lax.scan(body, vw_loc, None, length=1)
@@ -218,4 +230,5 @@ def iterate_spmd(lut: jax.Array, v_bits: jax.Array, cfg: BMVMConfig, r: int,
         return sm(lut_, vw_)
 
     out_w = run(lut, vw)
-    return kref.gf2_unpack_vector(out_w.astype(jnp.uint32), cfg.k)
+    with jax.named_scope("bmvm.unpack"):
+        return kref.gf2_unpack_vector(out_w.astype(jnp.uint32), cfg.k)

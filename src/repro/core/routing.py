@@ -12,6 +12,10 @@ this node addresses to node ``d``.  They return ``(n, *chunk)`` where
 variant is exactly the device transpose (``transpose_oracle``) — property
 tested in tests/test_routing*.py.
 
+Each ppermute round runs in the named scope ``routing.<axis>.r<t>`` (round t
+over mesh axis ``axis``), inside ``routing.all_to_all`` for the whole
+exchange, so a profile gives the device time of every round.
+
 A pure-numpy round-by-round simulator (``simulate_schedule``) executes the
 same schedules without devices; benchmarks use it so that measured time scales
 with rounds x bytes like the paper's Table V.
@@ -48,6 +52,11 @@ def _bwd_perm(n: int, wrap: bool) -> list[tuple[int, int]]:
     return list(bwd_pairs(n, wrap))
 
 
+def _round(axis_name: str, t: int):
+    """Named scope of round ``t`` (from 1) of an exchange over ``axis_name``."""
+    return jax.named_scope(f"routing.{axis_name}.r{t}")
+
+
 def _put(out: jax.Array, src, val: jax.Array, valid) -> jax.Array:
     """out[src] = val where valid (dynamic index, masked)."""
     src_c = jnp.clip(src, 0, out.shape[0] - 1)
@@ -64,11 +73,12 @@ def ring_all_to_all_unidir(x: jax.Array, axis_name: str) -> jax.Array:
     out = _put(jnp.zeros_like(x), i, me, True)
     buf = x
     for t in range(1, n):
-        buf = lax.ppermute(buf, axis_name, _fwd_perm(n, wrap=True))
-        # after t forward rotations this node holds node (i-t)'s buffer;
-        # extract the message it addressed to us.
-        val = lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-        out = _put(out, (i - t) % n, val, True)
+        with _round(axis_name, t):
+            buf = lax.ppermute(buf, axis_name, _fwd_perm(n, wrap=True))
+            # after t forward rotations this node holds node (i-t)'s buffer;
+            # extract the message it addressed to us.
+            val = lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
+            out = _put(out, (i - t) % n, val, True)
     return out
 
 
@@ -85,16 +95,17 @@ def line_all_to_all(x: jax.Array, axis_name: str, wrap: bool) -> jax.Array:
     bwd_steps = (n - 1) // 2 if wrap else n - 1
     fbuf, bbuf = x, x
     for t in range(1, max(fwd_steps, bwd_steps) + 1):
-        if t <= fwd_steps:
-            fbuf = lax.ppermute(fbuf, axis_name, _fwd_perm(n, wrap))
-            src = (i - t) % n if wrap else i - t
-            val = lax.dynamic_index_in_dim(fbuf, i, 0, keepdims=False)
-            out = _put(out, src, val, True if wrap else src >= 0)
-        if t <= bwd_steps:
-            bbuf = lax.ppermute(bbuf, axis_name, _bwd_perm(n, wrap))
-            src = (i + t) % n if wrap else i + t
-            val = lax.dynamic_index_in_dim(bbuf, i, 0, keepdims=False)
-            out = _put(out, src, val, True if wrap else src < n)
+        with _round(axis_name, t):
+            if t <= fwd_steps:
+                fbuf = lax.ppermute(fbuf, axis_name, _fwd_perm(n, wrap))
+                src = (i - t) % n if wrap else i - t
+                val = lax.dynamic_index_in_dim(fbuf, i, 0, keepdims=False)
+                out = _put(out, src, val, True if wrap else src >= 0)
+            if t <= bwd_steps:
+                bbuf = lax.ppermute(bbuf, axis_name, _bwd_perm(n, wrap))
+                src = (i + t) % n if wrap else i + t
+                val = lax.dynamic_index_in_dim(bbuf, i, 0, keepdims=False)
+                out = _put(out, src, val, True if wrap else src < n)
     return out
 
 
@@ -115,7 +126,8 @@ def grid_all_to_all(x: jax.Array, axis_x: str, axis_y: str, wrap: bool) -> jax.A
 
 def crossbar_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
     """Fat-tree / ideal crossbar: single fused all_to_all."""
-    return lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0)
+    with jax.named_scope("routing.all_to_all"):
+        return lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -234,16 +246,17 @@ def _line_exchange_compiled(x: jax.Array, phase: LinePhase,
     me = lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
     out = _put(jnp.zeros_like(x), i, me, True)
     bufs = [x, x]
-    for rnd in phase.rounds:
-        for mv in rnd.moves:
-            perm = expand(mv.perm) if expand is not None else list(mv.perm)
-            if transfer is None:
-                bufs[mv.buf] = lax.ppermute(bufs[mv.buf], name, perm)
-            else:
-                bufs[mv.buf] = transfer(bufs[mv.buf], perm)
-            src = jnp.asarray(mv.src_table, jnp.int32)[i]
-            val = lax.dynamic_index_in_dim(bufs[mv.buf], i, 0, keepdims=False)
-            out = _put(out, src, val, src >= 0)
+    for t, rnd in enumerate(phase.rounds, 1):
+        with _round(sched.axis, t):
+            for mv in rnd.moves:
+                perm = expand(mv.perm) if expand is not None else list(mv.perm)
+                if transfer is None:
+                    bufs[mv.buf] = lax.ppermute(bufs[mv.buf], name, perm)
+                else:
+                    bufs[mv.buf] = transfer(bufs[mv.buf], perm)
+                src = jnp.asarray(mv.src_table, jnp.int32)[i]
+                val = lax.dynamic_index_in_dim(bufs[mv.buf], i, 0, keepdims=False)
+                out = _put(out, src, val, src >= 0)
     return out
 
 
@@ -395,15 +408,18 @@ def topology_axes(topo: Topology) -> tuple[tuple[str, int], ...]:
 
 def all_to_all_for(topo: Topology):
     """Return fn(x) usable inside shard_map over ``topology_axes(topo)``."""
-    if isinstance(topo, Ring):
-        return lambda x: ring_all_to_all_unidir(x, "noc")
-    if isinstance(topo, Torus2D):  # subclass of Mesh2D — check first
-        return lambda x: grid_all_to_all(x, "noc_x", "noc_y", wrap=True)
-    if isinstance(topo, Mesh2D):
-        return lambda x: grid_all_to_all(x, "noc_x", "noc_y", wrap=False)
     if isinstance(topo, FatTree):
         return lambda x: crossbar_all_to_all(x, "noc")
-    raise TypeError(f"no schedule for {type(topo).__name__}")
+    if not isinstance(topo, (Ring, Mesh2D)):
+        raise TypeError(f"no schedule for {type(topo).__name__}")
+
+    def a2a(x):
+        with jax.named_scope("routing.all_to_all"):
+            if isinstance(topo, Ring):
+                return ring_all_to_all_unidir(x, "noc")
+            # Torus2D is a Mesh2D whose lines wrap
+            return grid_all_to_all(x, "noc_x", "noc_y", wrap=isinstance(topo, Torus2D))
+    return a2a
 
 
 # ---------------------------------------------------------------------------

@@ -99,5 +99,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :S, :]

@@ -60,5 +60,6 @@ def gf2_bmvm_pallas(lut: jax.Array, v_words: jax.Array, *, bm: int = 8,
         out_specs=pl.BlockSpec((bm, R), lambda m, c: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, R), jnp.uint32),
         interpret=interpret,
+        name="gf2_bmvm",
     )(v_words.astype(jnp.int32), lut)
     return out[:M]

@@ -75,5 +75,6 @@ def particle_histogram_pallas(bins: jax.Array, weights: jax.Array, ref_hist: jax
             jax.ShapeDtypeStruct((N + pad_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="histogram",
     )(bins_p, w_p, ref_hist[None, :].astype(jnp.float32))
     return hist[:N], bc[:N, 0]

@@ -46,5 +46,6 @@ def minsum_check_pallas(u: jax.Array, *, bc: int = 256, interpret: bool = False)
         out_specs=pl.BlockSpec((bc, deg), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, deg), u.dtype),
         interpret=interpret,
+        name="minsum_check",
     )(u)
     return out[:n]

@@ -3,9 +3,12 @@
 One naming scheme for every engine.  NoC engines publish their `NoCStats`
 under ``noc.*`` (flow counters as Counters, high-water marks as max-Gauges),
 MoE dispatch publishes ``noc.moe.*`` (`MoEDispatchStats.publish`), and the
-train/serve loops time their steps into latency Histograms
-(``train.step.seconds``, ``serve.prefill.seconds``, ``serve.decode.seconds``)
-with p50/p99/p99.9 read straight off the log buckets.  The per-step metric
+train/serve loops, when run with ``--metrics``, time their steps on the host
+clock into latency Histograms (``train.step.seconds``,
+``serve.prefill.seconds``, ``serve.decode.seconds``) with p50/p99/p99.9 read
+straight off the log buckets.  Those are host times, dispatch and host round
+trip included; the chip's own time is read from a profiler trace
+(``docs/observability.md``, "On the chip").  The per-step metric
 dict that `transformer.loss` returns maps onto the same names via
 :data:`STEP_METRIC_NAMES` — no more parallel ad-hoc dicts.
 

@@ -262,17 +262,16 @@ def test_compare_rows_counter_regression_and_improvement():
 
 
 def test_compare_rows_timing_tolerance_and_gate():
-    base = [{"name": "t_x", "us": 100.0}]
-    within = compare_rows(base, [{"name": "t_x", "us": 110.0}],
-                          timing_tol=0.25)
-    assert within == []                       # +10% inside 25% tol
-    beyond = compare_rows(base, [{"name": "t_x", "us": 200.0}],
-                          timing_tol=0.25)
-    assert beyond[0]["verdict"] == "regression"
-    assert beyond[0]["cls"] == "timing"
-    # gate off: timing can never fail
-    assert compare_rows(base, [{"name": "t_x", "us": 900.0}],
-                        gate_timing=False) == []
+    """Host wall times are reported, with their relative delta, and never
+    fail the gate, however far they move in either direction."""
+    base = [{"name": "t_x", "us": 100.0, "tok_per_s": 50.0}]
+    for us, tok in ((110.0, 55.0), (900.0, 5.0), (10.0, 500.0)):
+        found = compare_rows(base, [{"name": "t_x", "us": us, "tok_per_s": tok}])
+        assert {f["metric"] for f in found} == {"us", "tok_per_s"}
+        assert {(f["cls"], f["verdict"]) for f in found} == {("timing", "drift")}
+    slow = compare_rows(base, [{"name": "t_x", "us": 900.0, "tok_per_s": 50.0}])
+    assert [f["delta"] for f in slow] == ["+800.0%"]
+    assert compare_rows(base, [dict(base[0])]) == []
 
 
 def test_compare_rows_text_and_presence():
