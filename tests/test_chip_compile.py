@@ -61,6 +61,16 @@ CASES = {
     "gf2_bmvm_512x256x128_m1024": (
         gf2_bmvm_pallas, [((512, 256, 128), jnp.uint32), ((1024, 512), jnp.uint32)],
         "gf2_bmvm"),
+    # the benchmark's 9.66 GB LUT (n=24576, k=8) at M=128: the whole
+    # (128, 3072) output stays resident, two column tiles a step
+    "gf2_bmvm_3072x256x3072_m128": (
+        gf2_bmvm_pallas, [((3072, 256, 3072), jnp.uint32), ((128, 3072), jnp.uint32)],
+        "gf2_bmvm"),
+    # M=1024: a resident (1024, 3072) output and the LUT block overflow the
+    # 16 MiB of scoped VMEM, so the plan splits R
+    "gf2_bmvm_3072x256x3072_m1024": (
+        gf2_bmvm_pallas, [((3072, 256, 3072), jnp.uint32), ((1024, 3072), jnp.uint32)],
+        "gf2_bmvm"),
     # pg_ldpc_H(copies=186): 1302 checks of degree 3
     "minsum_1302x3": (minsum_check_pallas, [((1302, 3), jnp.float32)], "minsum_check"),
     # a wider check degree, at an 802.11n block length

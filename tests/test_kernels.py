@@ -6,19 +6,35 @@ from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import gf2_bmvm, ops, ref
 
 
 # -- GF(2) BMVM ---------------------------------------------------------------
 
-@pytest.mark.parametrize("n,k,m", [(16, 4, 1), (32, 4, 3), (64, 8, 5),
-                                   (128, 4, 2), (128, 8, 8)])
-def test_gf2_bmvm_kernel_vs_oracles(n, k, m):
+# (n, k, m, VMEM budget of the tile plan; None keeps the kernel's own)
+GF2_CASES = [pytest.param(n, k, m, None, id=f"{n}-{k}-{m}") for n, k, m in
+             [(16, 4, 1), (32, 4, 3), (64, 8, 5), (128, 4, 2), (128, 8, 8)]] + [
+    # C=32 column tiles: ct=4; M=13 is padded to 16
+    pytest.param(256, 8, 13, None, id="256-8-13"),
+    # R=256 words: a 40 KiB budget leaves rt=128, ct=1, grid (2, 256)
+    pytest.param(1024, 4, 21, 40 * 2 ** 10, id="1024-4-21-rt128"),
+    # R=384 words: a 48 KiB budget leaves rt=128, ct=2, grid (3, 192)
+    pytest.param(1536, 4, 5, 48 * 2 ** 10, id="1536-4-5-rt128-ct2"),
+]
+
+
+@pytest.mark.parametrize("n,k,m,budget", GF2_CASES)
+def test_gf2_bmvm_kernel_vs_oracles(n, k, m, budget, monkeypatch):
     rng = np.random.default_rng(n + k)
     A = jnp.asarray(rng.integers(0, 2, (n, n)), jnp.uint8)
     V = jnp.asarray(rng.integers(0, 2, (m, n)), jnp.uint8)
     lut = ref.gf2_preprocess(A, k)
     assert lut.shape == (n // k, 2 ** k, n // k)
+    if budget is not None:
+        monkeypatch.setattr(gf2_bmvm, "VMEM_BUDGET", budget)
+        jax.clear_caches()    # the plan is read when the kernel is traced
+        tp = gf2_bmvm.plan(m, *lut.shape)
+        assert tp.rt < lut.shape[2] and tp.vmem_bytes <= budget
     vw = ref.gf2_pack_vector(V, k).astype(jnp.uint32)
     out_k = ops.gf2_bmvm(lut, vw, use_kernel=True)
     out_r = ref.gf2_bmvm(lut, vw)
@@ -27,6 +43,53 @@ def test_gf2_bmvm_kernel_vs_oracles(n, k, m):
     direct = ref.gf2_matmul_oracle(A, V)
     assert np.array_equal(np.asarray(ref.gf2_unpack_vector(out_k, k)),
                           np.asarray(direct))
+
+
+@pytest.mark.parametrize("c,p,r", [(3072, 256, 3072), (512, 256, 512),
+                                   (512, 256, 128), (384, 16, 384), (8, 16, 8)])
+@pytest.mark.parametrize("m", [1, 5, 8, 128, 1000, 1024, 4096])
+def test_gf2_bmvm_tile_plan(m, c, p, r):
+    """One LUT pass for every shape, tiles that divide it, VMEM in budget."""
+    tp = gf2_bmvm.plan(m, c, p, r)
+    assert tp.lut_passes == 1
+    assert r % tp.rt == 0 and (tp.rt == r or tp.rt % 128 == 0)
+    assert c % tp.ct == 0 and 1 <= tp.ct <= gf2_bmvm.MAX_CT
+    assert tp.mp % 8 == 0 and 0 <= tp.mp - m < 8
+    assert tp.vmem_bytes <= gf2_bmvm.VMEM_BUDGET < 16 * 2 ** 20
+
+
+def test_gf2_bmvm_tile_plan_at_benchmark_shape():
+    """The 9.66 GB LUT: the whole output row stays resident at M <= 128;
+    M=1024 vectors (12 MiB of output) split R instead."""
+    for m in (1, 128):
+        tp = gf2_bmvm.plan(m, 3072, 256, 3072)
+        assert (tp.rt, tp.ct) == (3072, 2)
+    assert gf2_bmvm.plan(1024, 3072, 256, 3072).rt < 3072
+
+
+def test_gf2_bmvm_publishes_lut_traffic():
+    """``lut_passes`` and ``lut_bytes`` land in an enabled registry when the
+    kernel is traced, and nothing lands once it is disabled."""
+    from repro.telemetry.metrics import (MetricsRegistry, disable_metrics,
+                                         enable_metrics)
+    C, P, R = 8, 16, 8
+    lut = jnp.asarray(np.random.default_rng(0).integers(0, 2 ** 32, (C, P, R)),
+                      jnp.uint32)
+    vw = jnp.zeros((3, C), jnp.uint32)
+    reg = enable_metrics(MetricsRegistry())
+    try:
+        jax.clear_caches()
+        ops.gf2_bmvm(lut, vw)
+    finally:
+        disable_metrics()
+    assert reg.snapshot()["gauges"] == {"kernels.gf2_bmvm.lut_passes": 1,
+                                        "kernels.gf2_bmvm.lut_bytes": C * P * R * 4}
+    off = MetricsRegistry()
+    enable_metrics(off)
+    disable_metrics()
+    jax.clear_caches()
+    ops.gf2_bmvm(lut, vw)
+    assert off.snapshot()["gauges"] == {}
 
 
 @given(st.integers(0, 1000))
