@@ -45,9 +45,14 @@ class ModelConfig:
     pos_embed: str = "rope"          # rope | sinusoidal
     norm_eps: float = 1e-6
     # moe
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width, as published
     top_k: int = 0
     d_ff_expert: int = 0
+    # the experts held here, a contiguous block (0: all n_experts): one
+    # chip's share of an expert-parallel deployment
+    experts_held: int = 0
+    first_expert_held: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights
     moe_impl: str = "gather"         # gather | noc | dense
     moe_topology: str = "fattree"    # fattree | ring | mesh2d | torus2d
     capacity_factor: float = 1.25
@@ -75,6 +80,7 @@ class ModelConfig:
     remat: bool = True
     analysis_unroll: bool = False    # roofline analysis: unroll inner seq scans
     seq_shard_kv: bool = False       # long-context: shard KV/state seq over 'data'
+    source: str = ""                 # the published config these widths follow
 
     @property
     def vocab_padded(self) -> int:
@@ -114,8 +120,8 @@ class ModelConfig:
             n = 1
             for s in spec.shape:
                 n *= s
-            if self.n_experts in spec.shape and "experts" in spec.axes:
-                n = n // self.n_experts * self.top_k
+            if "experts" in spec.axes:
+                n = n // spec.shape[spec.axes.index("experts")] * self.top_k
             total += n
         return total
 

@@ -25,7 +25,7 @@ FULL = ModelConfig(
 SMOKE = FULL.replace(
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, vocab=256,
     n_experts=4, top_k=2, d_ff_expert=48, dtype="float32", remat=False,
-    attn_impl="naive", moe_impl="dense",
+    attn_impl="naive",
 )
 
 register(FULL, SMOKE)

@@ -141,13 +141,28 @@ def _blocked(q, k, v, causal: bool, kv_len, bkv: int, softcap: float, q_offset=N
     g = Hq // Hkv
     cdt = jnp.bfloat16 if compute_dtype == "bf16" else jnp.float32
     qg = (q.reshape(B, Hkv, g, S, D).astype(cdt)) * jnp.asarray(D ** -0.5, cdt)
-    kb = k.reshape(B, Hkv, nblk, bkv, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, Hkv, nblk, bkv, v.shape[-1]).transpose(2, 0, 1, 3, 4)
     q_ids = jnp.arange(S)[:, None]
+    t0s = jnp.arange(nblk) * bkv
+    if kv_len is None:
+        # no cache (training): the blocks as scan inputs, so that the backward
+        # pass gives each block its own cotangent
+        xs = (k.reshape(B, Hkv, nblk, bkv, D).transpose(2, 0, 1, 3, 4),
+              v.reshape(B, Hkv, nblk, bkv, v.shape[-1]).transpose(2, 0, 1, 3, 4), t0s)
 
-    def body(carry, blk):
+        def blocks(x):
+            return x
+    else:
+        # a cache: slice each block out of it where it lies, with no relayout
+        # (a copy of the whole cache on every call)
+        xs = t0s
+
+        def blocks(t0):
+            return (lax.dynamic_slice_in_dim(k, t0, bkv, axis=2),
+                    lax.dynamic_slice_in_dim(v, t0, bkv, axis=2), t0)
+
+    def body(carry, x):
         acc, m, lse = carry
-        kblk, vblk, t0 = blk
+        kblk, vblk, t0 = blocks(x)
         s = jnp.einsum("bhgsd,bhtd->bhgst", qg, kblk.astype(cdt),
                        preferred_element_type=jnp.float32)
         if softcap > 0:
@@ -173,8 +188,7 @@ def _blocked(q, k, v, causal: bool, kv_len, bkv: int, softcap: float, q_offset=N
     acc0 = jnp.zeros((B, Hkv, g, S, Dv), jnp.float32)
     m0 = jnp.full((B, Hkv, g, S, 1), -1e30, jnp.float32)
     lse0 = jnp.zeros((B, Hkv, g, S, 1), jnp.float32)
-    t0s = jnp.arange(nblk) * bkv
-    (acc, m, lse), _ = lax.scan(jax.checkpoint(body), (acc0, m0, lse0), (kb, vb, t0s),
+    (acc, m, lse), _ = lax.scan(jax.checkpoint(body), (acc0, m0, lse0), xs,
                               unroll=nblk if unroll else 1)
     o = acc / jnp.maximum(lse, 1e-30)
     return o.reshape(B, Hq, S, Dv).astype(q.dtype)
@@ -186,6 +200,12 @@ def attention(params: dict, x: jax.Array, c: AttnConfig, *,
               kv_override: Optional[tuple[jax.Array, jax.Array]] = None,
               causal: bool = True) -> tuple[jax.Array, Optional[dict]]:
     """x: (B, S, d).  Returns (out (B, S, d), updated cache or None)."""
+    with jax.named_scope("attn.mixer"):
+        return _attention(params, x, c, positions=positions, cache=cache,
+                          kv_override=kv_override, causal=causal)
+
+
+def _attention(params, x, c: AttnConfig, *, positions, cache, kv_override, causal):
     B, S, d = x.shape
     if positions is None:
         base = cache["idx"] if cache is not None else 0

@@ -6,7 +6,18 @@ Distributor / Data Collector wrappers; per-(src, expert) buffer capacity is
 the CONNECT flit-buffer-depth analog (tokens beyond capacity are dropped,
 exactly like a bounded FIFO back-pressuring).
 
-Two engines (both first-class, selectable per config):
+On one chip (no ``model`` mesh axis, or one of size 1) the layer runs
+``held``: the experts held here are a contiguous block of the router's
+``n_experts``; the router keeps its published width and top-k, and the held
+experts compute exactly the tokens routed to them, as a grouped matmul
+(``lax.ragged_dot``) over the (token, expert) pairs sorted by expert, with no
+capacity and no drops.  A token routed only to absent experts gets zero from
+this layer: what those experts add lies on other chips.  On a mesh of more
+than one device (data axes beside the size-1 ``model`` axis) it runs inside a
+``shard_map``, each device on its own shard of the batch.
+
+Over a ``model`` axis of more than one rank, two engines (both first-class,
+selectable per config):
 
 * ``gather`` — expert parallelism over model-axis-replicated activations:
   every model rank gathers the tokens addressed to its local experts
@@ -36,7 +47,8 @@ compiled flit programs: the (expert, slot) position inside the per-(src,dst)
 cube encodes the destination expert, so no header bytes ride the links —
 the same compile-time-contract framing `core.noc` uses for app graphs.
 
-Both engines implement the same math (property-tested against ``dense_ref``).
+Every path implements the same math (property-tested against ``dense_ref``,
+which stays the test oracle).
 """
 from __future__ import annotations
 
@@ -50,6 +62,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.noc import NoCConfig
+from ..telemetry.metrics import get_registry
 from .layers import ParamSpec
 
 
@@ -66,6 +79,17 @@ class MoEConfig:
     # NoC dispatch options: when set, flit_buffer_depth becomes the capacity
     # knob (capacity_factor is then *derived* — see dispatch_capacity)
     noc: Optional[NoCConfig] = None
+    # the experts held here: ``n_held`` (0: all ``n_experts``) from
+    # ``first_held`` on; ``n_experts`` stays the router's width
+    n_held: int = 0
+    first_held: int = 0
+    # renormalise the top-k gate weights to sum to one (Qwen3-MoE's
+    # ``norm_topk_prob``); Jamba keeps the softmax weights as they are
+    norm_topk_prob: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass
@@ -91,6 +115,7 @@ class MoEDispatchStats:
     link_bytes: int                 # bytes crossing topology links
     drops: Any = 0                  # tokens dropped by capacity (traced)
     peak_occupancy: Any = 0         # max tokens demanded of one (src,dst) buffer
+    experts: Any = None             # (B, S, top_k) routed expert ids (traced)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -109,9 +134,9 @@ class MoEDispatchStats:
 
 
 def moe_specs(c: MoEConfig, dtype=jnp.float32) -> dict:
-    E, d, f = c.n_experts, c.d_model, c.d_ff
+    E, d, f = c.held, c.d_model, c.d_ff
     return {
-        "router": ParamSpec((d, E), ("embed", None), dtype, init="small"),
+        "router": ParamSpec((d, c.n_experts), ("embed", None), dtype, init="small"),
         "gate": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"), dtype),
         "up": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"), dtype),
         "down": ParamSpec((E, f, d), ("experts", "expert_mlp", "embed"), dtype),
@@ -200,7 +225,8 @@ def _router(x_flat, wr, c: MoEConfig):
                          preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = lax.top_k(probs, c.top_k)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    if c.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
     # Switch-style load-balance loss terms (reduce across shards BEFORE the
     # product — mean-of-products != product-of-means)
     E = c.n_experts
@@ -211,18 +237,24 @@ def _router(x_flat, wr, c: MoEConfig):
 
 
 def dense_ref(params, x, c: MoEConfig):
-    """O(E·T·d·f) reference: every token through every expert, gate-combined.
-    The oracle for both engines (small shapes only)."""
+    """O(E·T·d·f) reference: every token through every held expert,
+    gate-combined.  The oracle for every path (small shapes only)."""
+    return _dense(params, x, c)[:2]
+
+
+def _dense(params, x, c: MoEConfig):
+    """``dense_ref`` and the routed ids, (B, S, top_k)."""
     B, S, d = x.shape
     xf = x.reshape(-1, d)
     w, idx, aux, _mece = _router(xf, params["router"], c)
     gate_full = jnp.zeros((xf.shape[0], c.n_experts), x.dtype)
     gate_full = jax.vmap(lambda g, i, ww: g.at[i].set(ww))(gate_full, idx, w)
+    gate_full = gate_full[:, c.first_held:c.first_held + c.held]
     h = jnp.einsum("td,edf->tef", xf, params["gate"].astype(x.dtype))
     u = jnp.einsum("td,edf->tef", xf, params["up"].astype(x.dtype))
     y = jnp.einsum("tef,efd->ted", _act(h, c.act) * u, params["down"].astype(x.dtype))
     out = jnp.einsum("ted,te->td", y, gate_full)
-    return out.reshape(B, S, d), aux
+    return out.reshape(B, S, d), aux, idx.reshape(B, S, c.top_k)
 
 
 def _expert_ffn(xe, wg, wu, wd, act):
@@ -230,6 +262,57 @@ def _expert_ffn(xe, wg, wu, wd, act):
     h = jnp.einsum("ecd,edf->ecf", xe, wg)
     u = jnp.einsum("ecd,edf->ecf", xe, wu)
     return jnp.einsum("ecf,efd->ecd", _act(h, act) * u, wd)
+
+
+# ---------------------------------------------------------------------------
+# one chip: the held experts, grouped, with no capacity
+# ---------------------------------------------------------------------------
+
+def grouped_ffn(xs, sizes, wg, wu, wd, act):
+    """Rows ``xs`` (P, d), sorted by held expert with ``sizes[e]`` rows for
+    expert e, each through its own expert: (P, d).  Rows past ``sum(sizes)``
+    belong to no expert; the caller masks them."""
+    h = lax.ragged_dot(xs, wg, sizes)
+    u = lax.ragged_dot(xs, wu, sizes)
+    return lax.ragged_dot(_act(h, act) * u, wd, sizes, preferred_element_type=jnp.float32)
+
+
+def _publish_held(c: MoEConfig) -> None:
+    """The router's width and the experts held, into the opt-in registry
+    (at trace time)."""
+    reg = get_registry()
+    if reg is not None:
+        reg.gauge("models.moe.router_width").set(c.n_experts)
+        reg.gauge("models.moe.experts_held").set(c.held)
+
+
+def _held_local(params, x_flat, c: MoEConfig):
+    """x_flat (T, d) -> (out (T, d), (me, ce), idx (T, k)): route over all
+    ``n_experts``, compute the held block on exactly the tokens routed to it.
+
+    The (token, expert) pairs are sorted by held expert, the pairs whose
+    expert is absent last; ``grouped_ffn`` runs each held expert on its run
+    of rows and the gate weights combine them back per token."""
+    T, d = x_flat.shape
+    k = c.top_k
+    _publish_held(c)
+    with jax.named_scope("moe.router"):
+        w, idx, _, mece = _router(x_flat, params["router"], c)
+        local = idx.reshape(-1) - c.first_held
+        mine = (local >= 0) & (local < c.held)
+        key = jnp.where(mine, local, c.held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((c.held + 1,), jnp.int32).at[key].add(1)[:c.held]
+        rows = order // k                                  # token of each sorted pair
+    with jax.named_scope("moe.experts"):
+        ys = grouped_ffn(x_flat[rows], sizes, params["gate"].astype(x_flat.dtype),
+                         params["up"].astype(x_flat.dtype),
+                         params["down"].astype(x_flat.dtype), c.act)
+    with jax.named_scope("moe.combine"):
+        gate = jnp.where(mine, w.reshape(-1).astype(jnp.float32), 0.0)[order]
+        ys = jnp.where(mine[order][:, None], ys * gate[:, None], 0.0)
+        out = ys[jnp.argsort(order)].reshape(T, k, d).sum(axis=1)
+    return out.astype(x_flat.dtype), mece, idx
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +349,7 @@ def _gather_local(x_flat, wr, wg, wu, wd, c: MoEConfig, n_ranks: int, axis: str,
     out = lax.psum(out, axis)                               # combine expert ranks
     counts = _dispatch_counts(flat_dst, blk_of_pkt, c.n_experts, n_blocks)
     drops, peak = _drops_and_peak(counts, cap, n_ranks)     # full-layer (replicated)
-    return out, (me, ce), (drops, peak)
+    return out, (me, ce), (drops, peak), idx
 
 
 # ---------------------------------------------------------------------------
@@ -319,7 +402,7 @@ def _noc_local(x_flat, wr, wg, wu, wd, c: MoEConfig, n_ranks: int, axis: str,
     out = out.at[toks.reshape(-1)].add(contrib.reshape(-1, d))
     counts = _dispatch_counts(flat_dst, blk0, E, 1)
     drops, peak = _drops_and_peak(counts, cap, n_ranks)      # this shard's share
-    return out, (me, ce), (drops, peak)
+    return out, (me, ce), (drops, peak), idx
 
 
 # ---------------------------------------------------------------------------
@@ -328,42 +411,32 @@ def _noc_local(x_flat, wr, wg, wu, wd, c: MoEConfig, n_ranks: int, axis: str,
 
 def _static_stats(engine: str, c: MoEConfig, *, fallback=None, topology=None,
                   capacity=0, tokens_per_src=0, flits=0, rounds=0,
-                  link_bytes=0, drops=0, peak=0) -> MoEDispatchStats:
+                  link_bytes=0, drops=0, peak=0, experts=None) -> MoEDispatchStats:
     cf = (effective_capacity_factor(tokens_per_src, c) if tokens_per_src
           else c.capacity_factor)
     return MoEDispatchStats(engine=engine, topology=topology, fallback=fallback,
                             capacity=capacity, capacity_factor=cf, flits=flits,
                             rounds=rounds, link_bytes=link_bytes, drops=drops,
-                            peak_occupancy=peak)
+                            peak_occupancy=peak, experts=experts)
 
 
 def moe_apply(params: dict, x: jax.Array, c: MoEConfig
               ) -> tuple[jax.Array, jax.Array, MoEDispatchStats]:
     """x: (B, S, d) -> (out, aux_loss, MoEDispatchStats).
 
-    Engine per ``c.impl``.  Every fallback away from the requested engine is
-    recorded in ``stats.fallback``; the silent-perf-cliff ones (expert count
-    not divisible across ranks, decode-shaped inputs demoting ``noc``) also
-    emit a ``UserWarning``.  The expected single-host no-mesh path records a
-    reason without warning."""
+    With no ``model`` mesh axis, or one of size 1, the ``held`` path runs
+    (one chip's experts, no drops); ``impl="dense"`` runs the oracle.  Over
+    more ranks the engine is ``c.impl``.  Every engine reports the routed
+    expert ids in ``stats.experts``.  Every fallback away from the
+    requested engine is recorded in ``stats.fallback``; the ones that cost
+    speed (expert count not divisible across ranks, decode-shaped inputs
+    demoting ``noc``) also emit a ``UserWarning``."""
     if c.impl == "dense":
-        out, aux = dense_ref(params, x, c)
-        return out, aux, _static_stats("dense", c)
+        out, aux, idx = _dense(params, x, c)
+        return out, aux, _static_stats("dense", c, experts=idx)
 
     mesh = jax.sharding.get_abstract_mesh()
-    if "model" not in mesh.axis_names:
-        # no mesh context (unit tests / single host): run the oracle
-        out, aux = dense_ref(params, x, c)
-        return out, aux, _static_stats(
-            "dense", c, fallback="no mesh context ('model' axis absent)")
-    n_ranks = mesh.shape["model"]
-    if c.n_experts % n_ranks:
-        reason = (f"n_experts={c.n_experts} not divisible by model ranks="
-                  f"{n_ranks}: dense_ref fallback, O(E*T*d*f) per token")
-        warnings.warn(f"moe_apply: {reason}", stacklevel=2)
-        out, aux = dense_ref(params, x, c)
-        return out, aux, _static_stats("dense", c, fallback=reason)
-
+    n_ranks = mesh.shape["model"] if "model" in mesh.axis_names else 1
     B, S, d = x.shape
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     n_batch = 1
@@ -373,6 +446,41 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
         batch_axes = ()          # tiny-batch decode: replicate over data axes
         n_batch = 1
     bspec = batch_axes if batch_axes else None
+    B_loc = B // n_batch
+
+    def _aux_of(me, ce, axes):
+        if axes:
+            me = lax.pmean(me, axes)
+            ce = lax.pmean(ce, axes)
+        return c.n_experts * jnp.sum(me * ce)
+
+    fallback = None
+    if n_ranks > 1 and c.n_experts % n_ranks:
+        fallback = (f"n_experts={c.n_experts} not divisible by model ranks="
+                    f"{n_ranks}: every rank computes all experts")
+        warnings.warn(f"moe_apply: {fallback}", stacklevel=2)
+    if n_ranks == 1 or fallback:
+        sharded = mesh.size > 1  # each device its own shard of the batch
+
+        def held(xl, p):
+            out, (me, ce), idx = _held_local(p, xl.reshape(-1, d), c)
+            return (out.reshape(xl.shape), _aux_of(me, ce, batch_axes if sharded else ()),
+                    idx.reshape(xl.shape[:2] + (c.top_k,)))
+        if sharded:
+            held = jax.shard_map(
+                held, mesh=mesh, in_specs=(P(bspec, None, None), P()),
+                out_specs=(P(bspec, None, None), P(), P(bspec, None, None)),
+                check_vma=False)
+        out, aux, idx = held(x, params)
+        T = B_loc * S
+        st = MoEDispatchStats(engine="held", topology=None, fallback=fallback,
+                              capacity=T * c.top_k, capacity_factor=float(c.n_experts),
+                              flits=0, rounds=0, link_bytes=0, experts=idx)
+        return out, aux.reshape(()), st
+    if c.held != c.n_experts:
+        raise ValueError(f"a share of {c.held} of {c.n_experts} experts runs on one "
+                         f"chip, not over {n_ranks} model ranks")
+
     wspec = P("model", None, None)
     all_axes = batch_axes + ("model",)
     impl, fallback = c.impl, None
@@ -382,14 +490,6 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
         warnings.warn(f"moe_apply: {fallback}", stacklevel=2)
         impl = "gather"
 
-    B_loc = B // n_batch
-
-    def _aux_of(me, ce, axes):
-        if axes:
-            me = lax.pmean(me, axes)
-            ce = lax.pmean(ce, axes)
-        return c.n_experts * jnp.sum(me * ce)
-
     if impl == "gather":
         T = B_loc * S
         # source blocks == the noc engine's sequence shards when S divides,
@@ -398,26 +498,26 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
         blk_of = (jnp.arange(T) % S) // (S // n_blocks)
 
         def fn(xl, wr, wg, wu, wd):
-            out, (me, ce), (drops, peak) = _gather_local(
+            out, (me, ce), (drops, peak), idx = _gather_local(
                 xl.reshape(T, d), wr, wg, wu, wd, c, n_ranks, "model",
                 blk_of, n_blocks)
             if batch_axes:       # drops replicated over 'model'; sum replicas
                 drops = lax.psum(drops, batch_axes)
                 peak = lax.pmax(peak, batch_axes)
             return (out.reshape(xl.shape), _aux_of(me, ce, batch_axes),
-                    drops, peak)
+                    drops, peak, idx.reshape(xl.shape[:2] + (c.top_k,)))
         sm = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(bspec, None, None), P(), wspec, wspec, wspec),
-            out_specs=(P(bspec, None, None), P(), P(), P()),
+            out_specs=(P(bspec, None, None), P(), P(), P(), P(bspec, None, None)),
             check_vma=False)
-        out, aux, drops, peak = sm(
+        out, aux, drops, peak, idx = sm(
             x, params["router"].astype(x.dtype), params["gate"].astype(x.dtype),
             params["up"].astype(x.dtype), params["down"].astype(x.dtype))
         stats = _static_stats("gather", c, fallback=fallback,
                               capacity=dispatch_capacity(T // n_blocks, c),
                               tokens_per_src=T // n_blocks,
-                              drops=drops, peak=peak)
+                              drops=drops, peak=peak, experts=idx)
         return out, aux.reshape(()), stats
 
     # impl == "noc": compile the topology's route program once per call site
@@ -435,16 +535,17 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
 
     def fn(xl, wr, wg, wu, wd):
         xl2 = xl.reshape(-1, d)
-        out, (me, ce), (drops, peak) = _noc_local(
+        out, (me, ce), (drops, peak), idx = _noc_local(
             xl2, wr, wg, wu, wd, c, n_ranks, "model", prog, cap)
         return (out.reshape(xl.shape), _aux_of(me, ce, all_axes),
-                lax.psum(drops, all_axes), lax.pmax(peak, all_axes))
+                lax.psum(drops, all_axes), lax.pmax(peak, all_axes),
+                idx.reshape(xl.shape[:2] + (c.top_k,)))
     sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(), wspec, wspec, wspec),
-        out_specs=(P(bspec, "model", None), P(), P(), P()),
+        out_specs=(P(bspec, "model", None), P(), P(), P(), P(bspec, "model", None)),
         check_vma=False)
-    out, aux, drops, peak = sm(
+    out, aux, drops, peak, idx = sm(
         x, params["router"].astype(x.dtype), params["gate"].astype(x.dtype),
         params["up"].astype(x.dtype), params["down"].astype(x.dtype))
     stats = _static_stats(
@@ -453,5 +554,5 @@ def moe_apply(params: dict, x: jax.Array, c: MoEConfig
         # out + back trips of the same program; flits frame all n^2 buffers
         flits=2 * n_ranks * n_ranks * ncfg.flits_for(msg_nbytes),
         rounds=2 * sstats.rounds, link_bytes=2 * sstats.link_bytes,
-        drops=drops, peak=peak)
+        drops=drops, peak=peak, experts=idx)
     return out, aux.reshape(()), stats

@@ -1,4 +1,9 @@
-"""Mamba-1 selective SSM block (Jamba's attention-free mixer).
+"""Mamba-1 selective SSM block, as Jamba's attention-free mixer has it.
+
+Jamba normalises the three outputs of ``x_proj`` before they enter the
+scan: dt (width ``dt_rank``), B and C (width ``d_state``) each pass an
+RMSNorm with a learned scale (arXiv:2403.19887 §6; ``modeling_jamba
+.JambaMambaMixer``).
 
 TPU adaptation: the CUDA selective-scan kernel fuses a sequential recurrence;
 on TPU we use a *chunked* scan — within a chunk the linear recurrence
@@ -19,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.partition import constrain
-from .layers import ParamSpec
+from .layers import ParamSpec, rms_norm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,6 +36,7 @@ class MambaConfig:
     dt_rank: int = 0     # 0 -> ceil(d/16)
     chunk: int = 256
     unroll: bool = False
+    eps: float = 1e-6    # the dt/B/C norms' epsilon (the model's rms_norm_eps)
 
     @property
     def d_inner(self) -> int:
@@ -48,6 +54,9 @@ def mamba_specs(c: MambaConfig, dtype=jnp.float32) -> dict:
         "conv_w": ParamSpec((c.d_conv, di), (None, "ssm_inner"), dtype, init="small"),
         "conv_b": ParamSpec((di,), ("ssm_inner",), dtype, init="zeros"),
         "x_proj": ParamSpec((di, R + 2 * N), ("ssm_inner", None), dtype),
+        "dt_norm": ParamSpec((R,), (None,), dtype, init="ones"),
+        "b_norm": ParamSpec((N,), ("ssm_state",), dtype, init="ones"),
+        "c_norm": ParamSpec((N,), ("ssm_state",), dtype, init="ones"),
         "dt_w": ParamSpec((R, di), (None, "ssm_inner"), dtype),
         "dt_b": ParamSpec((di,), ("ssm_inner",), dtype, init="ones", scale=-4.6),  # softplus^-1(~0.01)
         "a_log": ParamSpec((di, N), ("ssm_inner", "ssm_state"), dtype, init="ones"),
@@ -79,8 +88,14 @@ def _ssm_params(params, xc, c: MambaConfig):
     """xc (B,S,di) post-conv -> dt (B,S,di), B_in (B,S,N), C_out (B,S,N), A."""
     R, N = c.rank, c.d_state
     proj = xc @ params["x_proj"].astype(xc.dtype)
-    dt_r, b_in, c_out = proj[..., :R], proj[..., R:R + N], proj[..., R + N:]
-    # bias initialized to softplus^-1(~0.01) ≈ -4.6 (dt_b spec: ones × -4.6)
+
+    def norm(v, name):
+        return rms_norm(v, params[name].astype(v.dtype), c.eps)
+
+    dt_r = norm(proj[..., :R], "dt_norm")
+    b_in = norm(proj[..., R:R + N], "b_norm")
+    c_out = norm(proj[..., R + N:], "c_norm")
+    # dt_proj's bias is -4.6·dt_b: softplus^-1(~0.01) at init (dt_b = 1)
     dt = jax.nn.softplus(dt_r @ params["dt_w"].astype(xc.dtype)
                          - 4.6 * params["dt_b"].astype(xc.dtype))
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
@@ -104,6 +119,12 @@ def _chunk_recurrence(h0, decay, inc):
 def mamba_apply(params: dict, x: jax.Array, c: MambaConfig,
                 cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
     """x (B,S,d) -> (out (B,S,d), cache')."""
+    with jax.named_scope("mamba.mixer"):
+        return _mamba(params, x, c, cache)
+
+
+def _mamba(params: dict, x: jax.Array, c: MambaConfig,
+           cache: Optional[dict]) -> tuple[jax.Array, Optional[dict]]:
     B, S, d = x.shape
     di, N = c.d_inner, c.d_state
     xz = x @ params["in_proj"].astype(x.dtype)

@@ -57,7 +57,7 @@ def _mla_cfg(cfg: ModelConfig) -> mla_mod.MLAConfig:
 def _mamba_cfg(cfg: ModelConfig) -> ssm_mod.MambaConfig:
     return ssm_mod.MambaConfig(cfg.d_model, cfg.mamba_d_state, cfg.mamba_d_conv,
                                cfg.mamba_expand, chunk=cfg.mamba_chunk,
-                               unroll=cfg.analysis_unroll)
+                               unroll=cfg.analysis_unroll, eps=cfg.norm_eps)
 
 
 def _xlstm_cfg(cfg: ModelConfig) -> xlstm_mod.XLSTMConfig:
@@ -73,7 +73,9 @@ def _moe_cfg(cfg: ModelConfig) -> moe_mod.MoEConfig:
            if cfg.moe_flit_buffer_depth else None)
     return moe_mod.MoEConfig(cfg.d_model, cfg.n_experts, cfg.top_k, cfg.d_ff_expert,
                              capacity_factor=cfg.capacity_factor, impl=cfg.moe_impl,
-                             noc_topology=cfg.moe_topology, act=cfg.act, noc=noc)
+                             noc_topology=cfg.moe_topology, act=cfg.act, noc=noc,
+                             n_held=cfg.experts_held, first_held=cfg.first_expert_held,
+                             norm_topk_prob=cfg.norm_topk_prob)
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +174,8 @@ def _norm(x, gamma, cfg: ModelConfig):
 def _apply_sublayer(p, x, cfg: ModelConfig, mixer: str, ffn: str, *,
                     positions, cache, enc_out, causal):
     aux = jnp.zeros((), jnp.float32)
-    moe = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))  # (drops, peak)
+    # (drops, peak, routed expert ids or None)
+    moe = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32), None)
     h = _norm(x, p["norm1"], cfg)
     if mixer == "attn":
         o, new_cache = attention(p["attn"], h, _attn_cfg(cfg), positions=positions,
@@ -203,7 +206,7 @@ def _apply_sublayer(p, x, cfg: ModelConfig, mixer: str, ffn: str, *,
         h = _norm(x, p["norm2"], cfg)
         o, aux, st = moe_mod.moe_apply(p["moe"], h, _moe_cfg(cfg))
         moe = (jnp.asarray(st.drops, jnp.int32),
-               jnp.asarray(st.peak_occupancy, jnp.int32))
+               jnp.asarray(st.peak_occupancy, jnp.int32), st.experts)
         x = x + o
     return x, new_cache, aux, moe
 
@@ -213,7 +216,9 @@ def _run_stack(blocks, x, cfg: ModelConfig, *, pattern, positions, cache_blocks,
     """scan over periods; xs = (stacked period params, stacked period caches).
 
     MoE dispatch stats ride the carry: drops sum over layers, peak-occupancy
-    maxes (the hottest (src, dst) buffer anywhere in the stack)."""
+    maxes (the hottest (src, dst) buffer anywhere in the stack).  The routed
+    expert ids of the MoE layers leave as the scan's output, ``moe_experts``
+    (n_moe, B, S, k)."""
 
     def period_fn(carry, xs):
         x, aux, drops, peak = carry
@@ -222,25 +227,31 @@ def _run_stack(blocks, x, cfg: ModelConfig, *, pattern, positions, cache_blocks,
         else:
             pp, pc = xs, None
         new_pc = {}
+        routes = []
         for i, (mixer, ffn) in enumerate(pattern):
             sub_cache = pc[str(i)] if pc is not None else None
-            x, nc, a, (dr, pk) = _apply_sublayer(pp[str(i)], x, cfg, mixer, ffn,
-                                                 positions=positions, cache=sub_cache,
-                                                 enc_out=enc_out, causal=causal)
+            x, nc, a, (dr, pk, ex) = _apply_sublayer(
+                pp[str(i)], x, cfg, mixer, ffn, positions=positions, cache=sub_cache,
+                enc_out=enc_out, causal=causal)
             new_pc[str(i)] = nc if nc is not None else ()
             aux = aux + a
             drops = drops + dr
             peak = jnp.maximum(peak, pk)
+            if ex is not None:
+                routes.append(ex)
         x = constrain(x, ("batch", "seq", "embed"))
-        return (x, aux, drops, peak), (new_pc if pc is not None else 0)
+        routes = jnp.stack(routes) if routes else 0
+        return (x, aux, drops, peak), (new_pc if pc is not None else 0, routes)
 
     body = jax.checkpoint(period_fn) if cfg.remat else period_fn
     xs = (blocks, cache_blocks) if cache_blocks is not None else blocks
     carry0 = (x, jnp.zeros((), jnp.float32),
               jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-    (x, aux, drops, peak), new_caches = lax.scan(
+    (x, aux, drops, peak), (new_caches, routes) = lax.scan(
         body, carry0, xs, unroll=cfg.n_periods if cfg.analysis_unroll else 1)
     moe_stats = {"moe_drops": drops, "moe_peak_occupancy": peak}
+    if routes.ndim > 1:
+        moe_stats["moe_experts"] = routes.reshape((-1,) + routes.shape[2:])
     return x, aux, (new_caches if cache_blocks is not None else None), moe_stats
 
 
@@ -275,7 +286,9 @@ def forward(params: dict, batch: dict, cfg: ModelConfig,
 
     ``moe_stats``: {"moe_drops", "moe_peak_occupancy"} — capacity-dropped
     tokens summed over MoE layers and the hottest per-(src, dst) dispatch
-    buffer, straight from `moe.MoEDispatchStats` (zeros for dense archs)."""
+    buffer, straight from `moe.MoEDispatchStats` (zeros for dense archs) —
+    and, where the model has MoE layers, "moe_experts": the routed expert
+    ids (n_moe_layers, B, S, top_k)."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     pos0 = cache["pos"] if cache is not None else 0
@@ -324,7 +337,8 @@ def loss(params: dict, batch: dict, cfg: ModelConfig):
     nll = cross_entropy(logits, batch["labels"])
     total = nll + cfg.aux_weight * aux
     # f32 so downstream metric pmean/averaging is well-defined
-    mets = {k: v.astype(jnp.float32) for k, v in moe_stats.items()}
+    mets = {k: moe_stats[k].astype(jnp.float32)
+            for k in ("moe_drops", "moe_peak_occupancy")}
     return total, {"nll": nll, "aux": aux, **mets}
 
 

@@ -99,3 +99,31 @@ def test_iterate_kernel_names_its_kernel(one_chip, monkeypatch):
     fn = functools.partial(bmvm.iterate_kernel, cfg=bmvm.BMVMConfig(n=4096, k=8), r=1)
     hlo = _compiled_hlo(fn, one_chip, ((512, 256, 512), jnp.uint32), ((128, 4096), jnp.uint8))
     assert _named_kernel(hlo, "gf2_bmvm")
+
+
+@pytest.mark.parametrize("tokens", [16, 2048])
+def test_held_experts_compile_to_grouped_matmuls(tokens, one_chip):
+    """The one-chip MoE layer at Jamba's published widths (d 4096, expert
+    width 14336, router 16, top-2, 8 experts held), at a decode step of 16
+    tokens and a prefill chunk of 2048: each projection is one grouped
+    matmul that does the routed work alone (2 d f FLOPs a routed pair), not
+    a dense pass of every held expert over every token."""
+    from repro.models import moe as M
+
+    c = M.MoEConfig(4096, 16, 2, 14336, n_held=8, norm_topk_prob=False)
+    shapes = {"router": ((4096, 16), jnp.bfloat16),
+              **{k: ((8, 4096, 14336), jnp.bfloat16) for k in ("gate", "up")},
+              "down": ((8, 14336, 4096), jnp.bfloat16)}
+
+    def layer(x, router, gate, up, down):
+        params = {"router": router, "gate": gate, "up": up, "down": down}
+        return M.moe_apply(params, x, c)[0]
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [((1, tokens, 4096), jnp.bfloat16), *shapes.values()]]
+    compiled = jax.jit(layer).lower(*args).compile()
+    assert compiled.as_text().count("ragged-dot-metadata") >= 1
+    flops = compiled.cost_analysis()
+    flops = (flops[0] if isinstance(flops, list) else flops)["flops"]
+    routed = 2 * tokens * 2 * 3 * 4096 * 14336
+    assert routed <= flops < 1.1 * routed

@@ -186,13 +186,13 @@ mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
 rng = np.random.default_rng(3)
 x = jnp.asarray(rng.normal(size=(2, 8, 32)), jnp.float32)
 with jax.set_mesh(mesh):
-    # trigger 1: n_experts % n_ranks != 0 -> dense_ref (perf cliff), warns
+    # trigger 1: n_experts % n_ranks != 0 -> every rank holds all experts, warns
     bad = M.MoEConfig(32, 6, 2, 64, impl="gather")
     params = init_params(M.moe_specs(bad), jax.random.key(0))
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         _, _, st = M.moe_apply(params, x, bad)
-    assert st.engine == "dense" and "not divisible" in st.fallback
+    assert st.engine == "held" and "not divisible" in st.fallback
     assert any("not divisible" in str(m.message) for m in w)
     # trigger 2: decode-shaped input demotes noc -> gather, warns
     dec = M.MoEConfig(32, 8, 2, 64, impl="noc")
@@ -203,13 +203,13 @@ with jax.set_mesh(mesh):
         _, _, st = M.moe_apply(params, xd, dec)
     assert st.engine == "gather" and "decode-shaped" in st.fallback
     assert any("decode-shaped" in str(m.message) for m in w)
-# no mesh: expected single-host path — reason recorded, NO warning
+# no mesh: the one-chip held path — no fallback, NO warning
 c = M.MoEConfig(32, 8, 2, 64, impl="gather")
 params = init_params(M.moe_specs(c), jax.random.key(0))
 with warnings.catch_warnings(record=True) as w:
     warnings.simplefilter("always")
     _, _, st = M.moe_apply(params, x, c)
-assert st.engine == "dense" and "no mesh" in st.fallback
+assert st.engine == "held" and st.fallback is None
 assert not any("moe_apply" in str(m.message) for m in w)
 print("OK")
 """, n_devices=4)

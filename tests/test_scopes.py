@@ -155,3 +155,21 @@ def test_spmd_build_span(spmd):
     assert spmd["eager_builds"] == 2
     # the jitted caller traced once: later calls run the compiled program
     assert spmd["jitted_builds"] == 1
+
+
+def test_lm_decode_scopes():
+    """The served decode step of the hybrid model names its layers:
+    ``attn.mixer``, ``mamba.mixer``, and ``moe.router`` / ``moe.experts`` /
+    ``moe.combine`` in the one-chip held-experts layer."""
+    from repro.configs import get_config
+    from repro.launch import serve
+    from repro.models import transformer as T
+    from repro.models.layers import param_shapes
+
+    cfg = get_config("ai21-jamba2-mini", smoke=True).replace(n_experts=8, experts_held=4)
+    state = jax.eval_shape(lambda: serve.new_state(cfg, 2, 16))
+    logits = jax.ShapeDtypeStruct((2, cfg.vocab), jnp.float32)
+    hlo = serve.steps(cfg)[1].lower(param_shapes(T.abstract_params(cfg)), logits,
+                                    state).compile().as_text()
+    names = {c for o in hlo_ops(hlo) if o["op_name"] for c in o["op_name"].split("/")}
+    assert {"attn.mixer", "mamba.mixer", "moe.router", "moe.experts", "moe.combine"} <= names
