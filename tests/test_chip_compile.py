@@ -61,13 +61,18 @@ CASES = {
     "gf2_bmvm_512x256x128_m1024": (
         gf2_bmvm_pallas, [((512, 256, 128), jnp.uint32), ((1024, 512), jnp.uint32)],
         "gf2_bmvm"),
-    # the benchmark's 9.66 GB LUT (n=24576, k=8) at M=128: the whole
-    # (128, 3072) output stays resident, two column tiles a step
+    # the benchmark's LUT at M=1: the gather, which DMAs one aligned group
+    # of 8 rows a column tile out of the LUT left in HBM
+    "gf2_bmvm_3072x256x3072_m1": (
+        gf2_bmvm_pallas, [((3072, 256, 3072), jnp.uint32), ((1, 3072), jnp.uint32)],
+        "gf2_bmvm"),
+    # the benchmark's 9.66 GB LUT (n=24576, k=8) at M=128, on the stream:
+    # the whole (128, 3072) output stays resident, two column tiles a step
     "gf2_bmvm_3072x256x3072_m128": (
         gf2_bmvm_pallas, [((3072, 256, 3072), jnp.uint32), ((128, 3072), jnp.uint32)],
         "gf2_bmvm"),
-    # M=1024: a resident (1024, 3072) output and the LUT block overflow the
-    # 16 MiB of scoped VMEM, so the plan splits R
+    # M=1024, on the stream: a resident (1024, 3072) output and the LUT
+    # block overflow the 16 MiB of scoped VMEM, so the plan splits R
     "gf2_bmvm_3072x256x3072_m1024": (
         gf2_bmvm_pallas, [((3072, 256, 3072), jnp.uint32), ((1024, 3072), jnp.uint32)],
         "gf2_bmvm"),
